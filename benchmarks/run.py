@@ -1482,6 +1482,9 @@ SMOKE = ("fig7c", "table1", "transport", "scale_down", "scaleout", "teardown",
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = sys.argv[1:]
     smoke = "--smoke" in args
     only = list(SMOKE) if smoke else (args or list(BENCHES))

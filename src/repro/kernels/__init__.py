@@ -2,8 +2,10 @@
 
 Each kernel: ``<name>.py`` (pl.pallas_call + explicit BlockSpec VMEM
 tiling), jitted wrappers in ``ops.py``, pure-jnp oracles in ``ref.py``.
-Validated with interpret=True on CPU; the TPU path enables them via
-``ops.use_pallas``.
+Validated with interpret=True on CPU against the oracles, and compiled for
+a described TPU v5e in ``tests/test_tpu_compile.py``.  On the serving path
+``PagedServeEngine(attn_impl="kernel")`` calls ``paged_decode_attention``,
+compiled on a TPU; the other kernels are reached only from tests.
 """
 
 from . import ops, ref

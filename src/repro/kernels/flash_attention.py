@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -112,7 +111,7 @@ def flash_attention(q, k, v, *, block_q: int = 128, block_k: int = 128,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -242,7 +241,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, block_q: int = 128,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -262,7 +261,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, block_q: int = 128,
                    jax.ShapeDtypeStruct((B, S, H, D), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)

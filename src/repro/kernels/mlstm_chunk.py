@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -114,7 +113,7 @@ def mlstm_chunk(q, k, v, i_pre, f_pre, *, chunk: int = 64,
             pltpu.VMEM((1, dk), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -49,15 +49,15 @@ def decode_attention_ref(q, k_cache, v_cache, lengths):
 
 
 def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths):
-    """q (B,H,D); pools (N,bs,KV,D); block_tables (B,T); lengths (B,).
+    """q (B,H,D); pools (N,KV,bs,D); block_tables (B,T); lengths (B,).
 
     Gathers each sequence's blocks into a contiguous cache and defers to
     the dense oracle — the simplest statement of what paging must equal.
     """
     B = q.shape[0]
-    _, bs, KV, D = k_pool.shape
-    kc = k_pool[block_tables].reshape(B, -1, KV, D)
-    vc = v_pool[block_tables].reshape(B, -1, KV, D)
+    _, KV, bs, D = k_pool.shape
+    kc = k_pool[block_tables].transpose(0, 1, 3, 2, 4).reshape(B, -1, KV, D)
+    vc = v_pool[block_tables].transpose(0, 1, 3, 2, 4).reshape(B, -1, KV, D)
     return decode_attention_ref(q, kc, vc, lengths)
 
 

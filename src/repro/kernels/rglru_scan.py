@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_compat import CompilerParams
-
 
 def _rglru_kernel(la_ref, b_ref, o_ref, h_ref, *, block_t: int):
     it = pl.program_id(2)
@@ -64,7 +62,7 @@ def rglru_scan(log_a, b, *, block_b: int = 8, block_c: int = 128,
                                lambda ib, ic, it: (ib, it, ic)),
         out_shape=jax.ShapeDtypeStruct((B, S, C), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_b, block_c), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -23,9 +23,25 @@ def make_smoke_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")):
     return jax.make_mesh(shape, axes)
 
 
-HW = {
-    # TPU v5e-class roofline constants (per chip)
-    "peak_flops_bf16": 197e12,  # FLOP/s
-    "hbm_bw": 819e9,  # B/s
-    "ici_bw_per_link": 50e9,  # B/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+# interconnect over 4 links.
+V5E = "TPU v5 lite"
+PEAKS = {
+    V5E: {
+        "peak_flops_bf16": 197e12,  # FLOP/s
+        "hbm_bw": 819e9,  # B/s
+        "ici_bw_per_link": 1600e9 / 8 / 4,  # B/s per link
+    },
 }
+
+
+def peaks(device_kind: str) -> dict:
+    """The peaks of one chip of ``device_kind``; a kind without published
+    numbers in ``PEAKS`` is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; add them to PEAKS") from None

@@ -13,7 +13,11 @@ a trip-count-aware walk of the optimized (post-SPMD) HLO, because XLA's
 
 from __future__ import annotations
 
-from .mesh import HW
+from .mesh import V5E, peaks
+
+# the dry run targets the v5e production mesh (``mesh.make_production_mesh``)
+HW = peaks(V5E)
+
 
 def roofline_terms(flops_dev: float, bytes_dev: float,
                    collective_bytes_per_device: float, num_chips: int,

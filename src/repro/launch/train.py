@@ -1,15 +1,16 @@
 """Training launcher: run a (possibly sharded) training job directly, or
 submit it to the platform.
 
-Direct mode executes real steps on the available devices — used with
-XLA_FLAGS=--xla_force_host_platform_device_count=8 for multi-device CPU
-runs, or on a real TPU slice with the production mesh:
+Direct mode (``train_direct``) executes real steps on the available
+devices — with XLA_FLAGS=--xla_force_host_platform_device_count=4 for
+multi-device CPU runs, or on a four-chip TPU host with ``--mesh 2,2``
+(data, model):
 
   PYTHONPATH=src python -m repro.launch.train --arch gemma-2b --smoke \\
       --steps 20 --batch 8 --seq 64
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+  XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-14b --smoke \\
-      --mesh 2,2,2 --steps 10
+      --mesh 2,2 --steps 10
 
 Platform mode (--platform) submits a Job CRD and drives it through the
 cloud-native control plane (checkpointing, recovery, elasticity):
@@ -22,6 +23,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
 
 
 def main() -> None:
@@ -72,47 +74,76 @@ def main() -> None:
             p.shutdown()
         return
 
+    from ..configs import get_config, reduced_config
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    cfg = reduced_config(args.arch) if args.smoke else get_config(args.arch)
+    mesh_shape = (tuple(int(x) for x in args.mesh.split(","))
+                  if args.mesh else None)
+    train_direct(cfg, steps=args.steps, batch=args.batch, seq=args.seq,
+                 accum=args.accum, remat=not args.smoke,
+                 mesh_shape=mesh_shape)
+
+
+@dataclass
+class TrainRun:
+    state: dict  # train state after the last step
+    history: list  # per step: loss, grad_norm, seconds
+    compiled: object  # the compiled step (``as_text()``, memory analysis)
+
+
+def train_direct(cfg, *, steps: int, batch: int, seq: int, accum: int = 1,
+                 remat: bool = True, mesh_shape=None,
+                 seed: int = 0) -> TrainRun:
+    """Run ``steps`` train steps in this process.  Unsharded on the default
+    device, or over a mesh of ``mesh_shape`` (axes named from the right of
+    ``(pod, data, model)``, so ``(2, 2)`` is ``(data, model)``)."""
     import jax
 
-    from ..configs import get_config, reduced_config
     from ..data import StreamSource
     from ..models import ModelOptions
     from ..sharding.ctx import activation_rules
     from ..train import (TrainConfig, batch_sharding, init_train_state,
                          make_train_step, train_state_specs)
 
-    cfg = reduced_config(args.arch) if args.smoke else get_config(args.arch)
     opts = ModelOptions(compute_dtype="float32" if jax.default_backend() == "cpu"
                         else "bfloat16")
-    tcfg = TrainConfig(accum_steps=args.accum, remat=not args.smoke)
-    src = StreamSource(vocab_size=cfg.vocab_size, batch=args.batch,
-                       seq_len=args.seq, seed=0)
-    state = init_train_state(jax.random.key(0), cfg, tcfg)
+    tcfg = TrainConfig(accum_steps=accum, remat=remat)
+    src = StreamSource(vocab_size=cfg.vocab_size, batch=batch, seq_len=seq,
+                       seed=seed)
+    state = init_train_state(jax.random.key(seed), cfg, tcfg)
 
-    mesh = None
-    if args.mesh:
-        shape = tuple(int(x) for x in args.mesh.split(","))
-        axes = ("pod", "data", "model")[-len(shape):]
-        mesh = jax.make_mesh(shape, axes)
+    if mesh_shape:
+        axes = ("pod", "data", "model")[-len(mesh_shape):]
+        mesh = jax.make_mesh(mesh_shape, axes)
         specs = train_state_specs(state, mesh)
         state = jax.device_put(state, specs)
         bspecs = batch_sharding(mesh, src.batch_at(0))
         step = jax.jit(make_train_step(cfg, tcfg, opts, mesh=mesh,
                                        act_rules=activation_rules()),
                        in_shardings=(specs, bspecs), donate_argnums=0)
+
+        def place(b):
+            return jax.device_put(b, bspecs)
     else:
-        bspecs = None
         step = jax.jit(make_train_step(cfg, tcfg, opts), donate_argnums=0)
 
-    for i in range(args.steps):
-        batch = src.batch_at(i)
-        if bspecs is not None:
-            batch = jax.device_put(batch, bspecs)
-        t0 = time.time()
-        state, metrics = step(state, batch)
+        def place(b):
+            return b
+
+    compiled = step.lower(state, place(src.batch_at(0))).compile()
+    history = []
+    for i in range(steps):
+        b = place(src.batch_at(i))
+        t0 = time.perf_counter()
+        state, metrics = compiled(state, b)
         loss = float(metrics["loss"])
-        print(f"step {i:4d} loss {loss:9.4f} gnorm {float(metrics['grad_norm']):8.3f} "
-              f"({time.time() - t0:.2f}s)")
+        dt = time.perf_counter() - t0
+        gnorm = float(metrics["grad_norm"])
+        print(f"step {i:4d} loss {loss:9.4f} gnorm {gnorm:8.3f} ({dt:.2f}s)")
+        history.append({"loss": loss, "grad_norm": gnorm, "seconds": dt})
+    return TrainRun(state, history, compiled)
 
 
 if __name__ == "__main__":

@@ -6,6 +6,7 @@ from .lm import (
     forward_with_cache,
     init_cache,
     init_params,
+    init_serving_params,
     loss_fn,
     stack_plan,
 )
@@ -17,6 +18,7 @@ __all__ = [
     "forward",
     "init_cache",
     "init_params",
+    "init_serving_params",
     "loss_fn",
     "stack_plan",
 ]

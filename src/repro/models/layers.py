@@ -324,7 +324,9 @@ def init_mlp(key, d: int, d_ff: int, gated: bool, out_scale: float = 1.0) -> dic
     return p
 
 
-def dense_init(key, shape, scale: float = 1.0) -> jax.Array:
-    fan_in = max(shape[-2] if len(shape) >= 2 else 1, 1)
+def dense_init(key, shape, scale: float = 1.0, fan_in: int = 0) -> jax.Array:
+    """Normal(0, scale^2 / fan_in); ``fan_in`` defaults to ``shape[-2]``,
+    the contracted dim of a matrix or a stack of matrices."""
+    fan_in = fan_in or max(shape[-2] if len(shape) >= 2 else 1, 1)
     std = scale / math.sqrt(fan_in)
     return jax.random.normal(key, shape, jnp.float32) * std

@@ -117,11 +117,13 @@ def _init_layer(key, cfg: ArchConfig, spec: LayerSpec) -> dict:
     p: dict = {"norm1": init_rmsnorm(d)}
     if spec.kind in ("attn", "local"):
         hd, H, KV = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+        # fan-in is the contracted width: d for q/k/v, H*hd for the output
         attn = {
-            "wq": dense_init(ks[0], (d, H, hd)),
-            "wk": dense_init(ks[1], (d, KV, hd)),
-            "wv": dense_init(ks[2], (d, KV, hd)),
-            "wo": dense_init(ks[3], (H, hd, d), scale=1.0 / max(cfg.num_layers, 1) ** 0.5),
+            "wq": dense_init(ks[0], (d, H, hd), fan_in=d),
+            "wk": dense_init(ks[1], (d, KV, hd), fan_in=d),
+            "wv": dense_init(ks[2], (d, KV, hd), fan_in=d),
+            "wo": dense_init(ks[3], (H, hd, d), scale=1.0 / max(cfg.num_layers, 1) ** 0.5,
+                             fan_in=H * hd),
         }
         if cfg.qkv_bias:
             attn["bq"] = jnp.zeros((H, hd), jnp.float32)
@@ -149,7 +151,10 @@ def _init_layer(key, cfg: ArchConfig, spec: LayerSpec) -> dict:
     return p
 
 
-def init_params(key, cfg: ArchConfig) -> dict:
+def init_params(key, cfg: ArchConfig, dtype=jnp.float32) -> dict:
+    """Random parameters from ``key``, stored in ``dtype``.  The main
+    groups are built stacked (``vmap`` over group keys), so no per-group
+    copy is ever stacked; ``init_serving_params`` runs this under ``jit``."""
     plan = stack_plan(cfg)
     ks = jax.random.split(key, 8)
     params: dict = {
@@ -170,20 +175,25 @@ def init_params(key, cfg: ArchConfig) -> dict:
             return [_init_layer(kk[i], cfg, s) for i, s in enumerate(plan.pattern)]
 
         group_keys = jax.random.split(ks[4], plan.num_groups)
-        per_group = [init_group(k) for k in group_keys]
-        params["main"] = jax.tree.map(lambda *xs: jnp.stack(xs), *per_group)
+        params["main"] = jax.vmap(init_group)(group_keys)
     else:
         params["main"] = []
     params["tail"] = [
         _init_layer(k, cfg, s)
         for k, s in zip(jax.random.split(ks[5], max(len(plan.tail), 1)), plan.tail)
     ]
-    return params
+    return jax.tree.map(lambda x: x.astype(dtype), params)
 
 
-def abstract_params(cfg: ArchConfig) -> dict:
+def init_serving_params(key, cfg: ArchConfig, dtype=jnp.bfloat16) -> dict:
+    """``init_params`` compiled as one program that writes ``dtype``
+    weights: no float32 copy of the model is ever held on the device."""
+    return jax.jit(init_params, static_argnums=(1, 2))(key, cfg, dtype)
+
+
+def abstract_params(cfg: ArchConfig, dtype=jnp.float32) -> dict:
     """Parameter ShapeDtypeStructs without allocation (dry-run path)."""
-    return jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
+    return jax.eval_shape(lambda: init_params(jax.random.key(0), cfg, dtype))
 
 
 # ------------------------------------------------------------------ forward

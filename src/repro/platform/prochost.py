@@ -356,6 +356,9 @@ class HostBridge:
         env["PYTHONPATH"] = src_root + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
         env["REPRO_WORKER_NODE"] = node
+        # a chip belongs to the process that opened it first; workers host
+        # host-side PEs only and must never reach for the accelerator
+        env["JAX_PLATFORMS"] = "cpu"
         env["REPRO_WORKER_PARENT"] = f"{self.address[0]}:{self.address[1]}"
         proc = subprocess.Popen(
             [sys.executable, "-c",

@@ -295,6 +295,10 @@ class PagedServeEngine:
         self.cow_copies = 0
         self.peak_active = 0
         self.on_metrics: Optional[Callable[[dict], None]] = None
+        # called with (request, logits row) when a prompt completes: the
+        # logits its first token is sampled from
+        self.on_first_logits: Optional[Callable[[Request, np.ndarray],
+                                                None]] = None
 
     # ----------------------------------------------------------- admission
 
@@ -450,6 +454,8 @@ class PagedServeEngine:
                     # tick — same semantics as ServeEngine._admit) and
                     # publish the prompt's blocks for prefix reuse
                     s.next_token = int(np.argmax(logits[i]))
+                    if self.on_first_logits is not None:
+                        self.on_first_logits(s.req, logits[i])
                     if self.cache is not None:
                         self.cache.insert(s.req.prompt, s.seq.blocks, P)
             else:

@@ -2,7 +2,8 @@
 
 ``init_paged_state`` mirrors ``models.lm.init_cache`` but replaces every
 global-attention layer's ``(B, max_len, KV, D)`` cache with a shared block
-pool ``(num_blocks, block_size, KV, D)`` — sequences address it through a
+pool ``(num_blocks, KV, block_size, D)`` (the layout the paged kernel
+reads block by block) — sequences address it through a
 per-slot block table, so cache memory is proportional to tokens actually
 held, not ``slots x max_len``.  Non-attention state (sliding-window ring
 buffers, recurrent states) stays per-slot: it is O(window) / O(1) per
@@ -47,12 +48,8 @@ def _is_paged(spec) -> bool:
 
 def _init_entry(cfg, spec, max_active, num_blocks, block_size, dtype):
     if _is_paged(spec):
-        return {
-            "k": jnp.zeros((num_blocks, block_size, cfg.num_kv_heads,
-                            cfg.head_dim), dtype),
-            "v": jnp.zeros((num_blocks, block_size, cfg.num_kv_heads,
-                            cfg.head_dim), dtype),
-        }
+        shape = (num_blocks, cfg.num_kv_heads, block_size, cfg.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     # _init_layer_state only uses max_len to clamp the local window
     return _init_layer_state(cfg, spec, max_active, cfg.window or 1, dtype)
 
@@ -118,23 +115,22 @@ def _paged_attn_layer(lparams, cfg, spec, state, x, sin, cos, lengths, adv,
     q = apply_rope(q, sin, cos)
     k = apply_rope(k, sin, cos)
 
-    bs = state["k"].shape[1]
+    _, KV, bs, D = state["k"].shape
     bidx = jnp.arange(x.shape[0])
-    # inactive rows write the scratch block (0, 0); their output is
+    # inactive rows write the scratch block (0, :, 0); their output is
     # ignored by the caller, so no gather/scatter is ever masked out
     blk = jnp.where(adv, tables[bidx, lengths // bs], 0)
     off = jnp.where(adv, lengths % bs, 0)
-    new_k = state["k"].at[blk, off].set(k)
-    new_v = state["v"].at[blk, off].set(v)
+    new_k = state["k"].at[blk, :, off].set(k)  # k (B, KV, D)
+    new_v = state["v"].at[blk, :, off].set(v)
 
     if attn_impl == "kernel":
         out = paged_decode_attention(q, new_k, new_v, tables, lengths + 1,
                                      interpret=interpret)
     else:  # pure-jnp gather: XLA materializes each slot's view on gather
         B = x.shape[0]
-        KV, D = new_k.shape[2], new_k.shape[3]
-        kc = new_k[tables].reshape(B, -1, KV, D)
-        vc = new_v[tables].reshape(B, -1, KV, D)
+        kc = new_k[tables].transpose(0, 1, 3, 2, 4).reshape(B, -1, KV, D)
+        vc = new_v[tables].transpose(0, 1, 3, 2, 4).reshape(B, -1, KV, D)
         out = decode_attention_jnp(q, kc, vc, lengths + 1)
     mix = jnp.einsum("bhe,hed->bd", out, ap["wo"].astype(dt))
     x = x + mix
@@ -239,12 +235,13 @@ def make_paged_tick(cfg, opts: ModelOptions = ModelOptions(), *,
                                         jnp.arange(C, dtype=jnp.int32))
         return last, state
 
-    return jax.jit(tick)
+    return jax.jit(tick, donate_argnums=1)
 
 
 def make_copy_block(cfg):
     """Jitted pool-slab copy ``src -> dst`` across every paged layer — the
-    device half of copy-on-write (the allocator decides *when*)."""
+    device half of copy-on-write (the allocator decides *when*).  The state
+    is donated, as in the tick."""
     plan = stack_plan(cfg)
 
     def copy_entry(spec, entry, src, dst, stacked):
@@ -267,14 +264,15 @@ def make_copy_block(cfg):
             out["main"] = []
         return out
 
-    return jax.jit(copy)
+    return jax.jit(copy, donate_argnums=0)
 
 
 def make_reset_slot(cfg):
     """Jitted per-slot reset for admission: zero the slot's rows of every
     *per-slot* (non-paged) state leaf and seed its length with the number
     of prefix-cached tokens it adopts.  Paged pools need no reset — block
-    contents beyond a sequence's length are masked by construction."""
+    contents beyond a sequence's length are masked by construction.  The
+    state is donated, as in the tick."""
     plan = stack_plan(cfg)
 
     def reset_entry(spec, entry, slot, stacked):
@@ -301,4 +299,4 @@ def make_reset_slot(cfg):
             out["main"] = []
         return out
 
-    return jax.jit(reset)
+    return jax.jit(reset, donate_argnums=0)

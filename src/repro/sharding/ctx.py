@@ -17,7 +17,7 @@ from contextlib import contextmanager
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _state = threading.local()
 
@@ -26,10 +26,27 @@ def _current() -> Optional[tuple]:
     return getattr(_state, "binding", None)
 
 
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``.
+
+    The model places activations with sharding constraints and leaves the
+    rest to the compiler's propagation, which JAX allows on ``Auto`` axes
+    only; ``jax.make_mesh`` makes ``Explicit`` axes by default.
+    """
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 @contextmanager
 def use_rules(mesh: Mesh, rules: dict):
+    """Bind logical names to ``mesh``.  Rule targets the mesh lacks (e.g.
+    ``pod`` on a 2-D ``(data, model)`` mesh) are dropped, so one rule set
+    serves every mesh shape."""
     prev = _current()
-    _state.binding = (mesh, dict(rules))
+    missing = {a for v in rules.values() if v is not None
+               for a in (v if isinstance(v, tuple) else (v,))
+               if a not in mesh.axis_names}
+    _state.binding = (auto_axes(mesh), _strip_axes(rules, missing))
     try:
         yield
     finally:
@@ -46,20 +63,6 @@ def resolve(axes: tuple, rules: dict) -> P:
     return P(*parts)
 
 
-def _abstract_mesh():
-    """The current abstract mesh, or None where JAX doesn't expose one.
-
-    ``jax.sharding.get_abstract_mesh`` is a newer API; on older JAX (which
-    also predates Manual axis types on abstract meshes) we fall back to the
-    concrete bound mesh — correct here because the compressed-gradient path
-    is pure pjit, never an actual shard_map manual region.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return None
-    return get()
-
-
 def shard(x: jax.Array, axes: tuple) -> jax.Array:
     """Constrain ``x`` to the sharding implied by logical ``axes``.
 
@@ -72,8 +75,8 @@ def shard(x: jax.Array, axes: tuple) -> jax.Array:
     if binding is None:
         return x
     mesh, rules = binding
-    abstract = _abstract_mesh()
-    if abstract is not None and not abstract.empty:
+    abstract = jax.sharding.get_abstract_mesh()
+    if not abstract.empty:
         manual = {name for name, kind in zip(abstract.axis_names,
                                              abstract.axis_types)
                   if str(kind).endswith("Manual")}

@@ -20,6 +20,8 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.tree_util import DictKey, SequenceKey
 
+from .ctx import auto_axes
+
 
 PARAM_RULES = {
     "embed_p": "data",
@@ -144,6 +146,7 @@ def fit_spec(spec: P, shape: tuple, mesh: Mesh) -> P:
 
 def param_specs(params, mesh: Mesh, rules: dict = PARAM_RULES):
     """Pytree of NamedShardings for a (possibly abstract) parameter tree."""
+    mesh = auto_axes(mesh)
     logical = param_logical_axes(params)
     return jax.tree.map(
         lambda leaf, ax: NamedSharding(

@@ -25,7 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ArchConfig
 from ..models import ModelOptions, init_params, loss_fn
-from ..sharding.ctx import use_rules
+from ..sharding.ctx import auto_axes, use_rules
 from ..sharding.specs import PARAM_RULES, param_specs
 from .compress import compressed_mean_over_axis, init_ef_state
 from .optim import OptimizerConfig, adamw_update, clip_by_global_norm, init_opt_state
@@ -58,6 +58,7 @@ def abstract_train_state(cfg: ArchConfig, tcfg: TrainConfig = TrainConfig()):
 
 def train_state_specs(state, mesh: Mesh, rules: dict = PARAM_RULES):
     """NamedShardings for a (possibly abstract) train state."""
+    mesh = auto_axes(mesh)
     p_specs = param_specs(state["params"], mesh, rules)
     specs = {
         "params": p_specs,
@@ -85,6 +86,8 @@ def train_state_specs(state, mesh: Mesh, rules: dict = PARAM_RULES):
 
 
 def batch_sharding(mesh: Mesh, batch, data_axes: tuple = ("pod", "data")):
+    """Batch leaves split over whichever of ``data_axes`` the mesh has."""
+    mesh = auto_axes(mesh)
     axes = tuple(a for a in data_axes if a in mesh.axis_names)
     return jax.tree.map(lambda _: NamedSharding(mesh, P(axes)), batch)
 
@@ -121,6 +124,8 @@ def make_train_step(cfg: ArchConfig, tcfg: TrainConfig = TrainConfig(),
                     act_rules: Optional[dict] = None):
     """Returns step(state, batch) -> (state, metrics)."""
     ocfg = tcfg.optimizer
+    if mesh is not None:
+        mesh = auto_axes(mesh)
 
     def apply_update(state, grads, loss, metrics):
         grads, gnorm = clip_by_global_norm(grads, ocfg.clip_norm)

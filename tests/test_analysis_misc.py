@@ -135,6 +135,7 @@ def test_straggler_monitor_marks_stale_pods():
                                            "stragglerTimeout": 5.0}))
         pod = crds.make_pod("j", 0, {}, launch_count=1, generation=1)
         p.store.create(pod)
+        stale_uid = p.store.get(crds.POD, pod.name).uid
         p.store.update_status(crds.POD, pod.name,
                               {"phase": "Running", "heartbeat": time.time() - 60})
         fresh = crds.make_pod("j", 1, {}, launch_count=1, generation=1)
@@ -144,8 +145,14 @@ def test_straggler_monitor_marks_stale_pods():
         marked = p.straggler_monitor.scan()
         assert marked == [pod.name]
         # the normal failure causal chain takes over: pod controller deletes
-        # the failed pod (kind may already be deleted by the controller)
-        assert wait_for(lambda: not p.store.exists(crds.POD, pod.name), 10)
+        # the failed pod (kind may already be deleted by the controller).
+        # The job's own pod conductor may reuse the name for a new pod, so
+        # the stale incarnation is tracked by uid.
+        def stale_gone():
+            cur = p.store.try_get(crds.POD, pod.name)
+            return cur is None or cur.uid != stale_uid
+
+        assert wait_for(stale_gone, 10)
         assert p.store.exists(crds.POD, fresh.name)
     finally:
         p.shutdown()

@@ -100,8 +100,8 @@ def test_paged_decode_attention_parity(B, H, KV, D, bs, T, dtype):
     num_blocks = B * T + 1  # + scratch block 0
     ks = jax.random.split(jax.random.key(hash((B, H, KV, D, bs)) % 2**31), 4)
     q = rand(ks[0], (B, H, D), dtype)
-    k_pool = rand(ks[1], (num_blocks, bs, KV, D), dtype)
-    v_pool = rand(ks[2], (num_blocks, bs, KV, D), dtype)
+    k_pool = rand(ks[1], (num_blocks, KV, bs, D), dtype)
+    v_pool = rand(ks[2], (num_blocks, KV, bs, D), dtype)
     perm = jax.random.permutation(ks[3], num_blocks - 1) + 1
     tables = perm.reshape(B, T).astype(jnp.int32)
     lengths = jnp.asarray([max(1, (T * bs) // (i + 1) - 3) for i in range(B)],
@@ -123,12 +123,12 @@ def test_paged_matches_dense_same_tokens():
     num_blocks = B * T + 1
     ks = jax.random.split(jax.random.key(12), 4)
     q = rand(ks[0], (B, H, D), jnp.float32)
-    k_pool = rand(ks[1], (num_blocks, bs, KV, D), jnp.float32)
-    v_pool = rand(ks[2], (num_blocks, bs, KV, D), jnp.float32)
+    k_pool = rand(ks[1], (num_blocks, KV, bs, D), jnp.float32)
+    v_pool = rand(ks[2], (num_blocks, KV, bs, D), jnp.float32)
     tables = (jnp.arange(B * T, dtype=jnp.int32).reshape(B, T) + 1)
     lengths = jnp.asarray([T * bs, T * bs - 5], jnp.int32)
-    kc = k_pool[tables].reshape(B, T * bs, KV, D)
-    vc = v_pool[tables].reshape(B, T * bs, KV, D)
+    kc = k_pool[tables].transpose(0, 1, 3, 2, 4).reshape(B, T * bs, KV, D)
+    vc = v_pool[tables].transpose(0, 1, 3, 2, 4).reshape(B, T * bs, KV, D)
     dense = decode_attention(q, kc, vc, lengths, block_k=bs, interpret=True)
     paged = paged_decode_attention(q, k_pool, v_pool, tables, lengths,
                                    interpret=True)

@@ -15,6 +15,7 @@ from repro.models import (
     forward_with_cache,
     init_cache,
     init_params,
+    init_serving_params,
     loss_fn,
 )
 from repro.models.layers import (
@@ -68,6 +69,22 @@ def test_prefill_decode_equivalence(arch):
         lg, cache = step(cache, toks[:, t])
         errs.append(float(jnp.max(jnp.abs(lg - full[:, t]))))
     assert max(errs) < 5e-3, errs
+
+
+@pytest.mark.parametrize("arch", ["qwen1.5-4b", "recurrentgemma-9b",
+                                  "xlstm-125m"])
+def test_serving_params_match_init_params(arch):
+    """The jitted bf16 init builds init_params' tree, shapes and values,
+    rounded to bf16."""
+    cfg = reduced_config(arch)
+    want = init_params(jax.random.key(3), cfg)
+    got = init_serving_params(jax.random.key(3), cfg, jnp.bfloat16)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32),
+                                   rtol=1e-2, atol=1e-6)
 
 
 def test_tree_attention_exact():

@@ -1,9 +1,11 @@
 """Multi-device sharding correctness: run a REAL sharded train step on 8
-forced host devices (subprocess — device count must be set before jax init)
-and compare against the single-device result.  Also covers the cell builder
+forced host devices, and on 4 as a (data, model) mesh like one four-chip
+host (subprocess — device count must be set before jax init), and compare
+against the single-device result.  Also covers the cell builder
 and divisibility-aware specs on a small mesh."""
 
 import json
+import math
 import os
 import subprocess
 import sys
@@ -49,8 +51,7 @@ SHARDED_VS_SINGLE = textwrap.dedent("""
     s1, m1 = step1(s1, src.batch_at(1))
     ref_loss = float(m1["loss"])
 
-    # sharded: (pod, data, model) = (2, 2, 2)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh(MESH_SHAPE, MESH_AXES)
     rules = activation_rules()
     state2 = init_train_state(jax.random.key(0), cfg, tcfg)
     specs = train_state_specs(state2, mesh)
@@ -71,11 +72,23 @@ SHARDED_VS_SINGLE = textwrap.dedent("""
 """)
 
 
-@pytest.mark.slow
-def test_sharded_train_step_matches_single_device():
-    r = run_py(SHARDED_VS_SINGLE)
+def check_sharded_vs_single(shape, axes):
+    code = (SHARDED_VS_SINGLE.replace("MESH_SHAPE", repr(shape))
+            .replace("MESH_AXES", repr(axes)))
+    r = run_py(code, devices=math.prod(shape))
     assert abs(r["ref_loss"] - r["sh_loss"]) < 1e-3, r
     assert r["max_param_diff"] < 1e-4, r
+
+
+@pytest.mark.slow
+def test_sharded_train_step_matches_single_device():
+    check_sharded_vs_single((2, 2, 2), ("pod", "data", "model"))
+
+
+@pytest.mark.slow
+def test_sharded_train_step_on_four_device_data_model_mesh():
+    """The mesh of one four-chip host: no ``pod`` axis."""
+    check_sharded_vs_single((2, 2), ("data", "model"))
 
 
 COMPRESSED_GRADS = textwrap.dedent("""
