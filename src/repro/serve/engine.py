@@ -13,10 +13,20 @@ admission runs as *chunked prefill* interleaved with decode inside one
 jitted mixed tick (``paged_model.py``); and committed prompt blocks are
 shared across requests through a refcounted prefix cache with
 copy-on-write on divergence.
+
+Each ``PagedServeEngine.step`` is one ``serve.step`` host span in the
+profiler's trace, holding one span per phase (``serve.admit``,
+``serve.prepare``, ``serve.dispatch``, ``serve.tick_wait``,
+``serve.logits_copy``, ``serve.sample``, ``serve.cache_insert`` and
+``serve.hook`` around user callbacks), so the device's idle gaps can be put
+down to host work on the trace's clock.  The step span carries the step's
+work as metadata.
 """
 
 from __future__ import annotations
 
+import gc
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Optional
@@ -24,6 +34,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..configs.base import ArchConfig
 from ..models import ModelOptions, decode_step, forward_with_cache, init_cache
@@ -36,6 +47,24 @@ from .paged_model import (
     make_reset_slot,
 )
 from .paging import BlockAllocator, OutOfBlocks, PrefixCache, SequenceBlocks
+
+# Seconds this process has spent in Python's garbage collector, kept by one
+# ``gc.callbacks`` entry that the first ``PagedServeEngine`` registers; a
+# step reads the difference across itself.
+_gc = {"total": 0.0, "since": 0.0, "timed": False}
+
+
+def _gc_timer(phase: str, info: dict) -> None:
+    if phase == "start":
+        _gc["since"] = time.perf_counter()
+    else:
+        _gc["total"] += time.perf_counter() - _gc["since"]
+
+
+def _time_gc() -> None:
+    if not _gc["timed"]:
+        _gc["timed"] = True
+        gc.callbacks.append(_gc_timer)
 
 
 def make_prefill_step(cfg: ArchConfig, opts: ModelOptions = ModelOptions(),
@@ -240,6 +269,7 @@ class _PagedSlot:
     pos: int  # prompt tokens fed so far (== cached tokens at admission)
     next_token: int = 0  # next decode feed once prefill completed
     reserved: int = 0  # future block demand still counted in the reserve
+    tick0: int = 0  # engine ticks run before admission
 
 
 class PagedServeEngine:
@@ -253,6 +283,15 @@ class PagedServeEngine:
     long admission delays running decodes by at most ``prefill_chunk - 1``
     masked micro-steps instead of a full O(prompt) blocking loop.  Greedy
     decoding, same output semantics as ``ServeEngine``.
+
+    Each ``step`` is one ``serve.step`` span in the profiler's trace, its
+    phases ``serve.*`` spans inside it.  When a profiler records, the step
+    span carries the step's work as metadata: ``C``, the tick's width
+    (``prefill_chunk`` while a row prefills, 1 when every row decodes, 0
+    when no tick ran); ``rows`` issued; prompt tokens ``fed`` and tokens
+    ``decoded``; ``first``, ``"rid:ticks"`` pairs of the requests whose
+    prompt completed, with the ticks from admission to the prompt's last
+    logits; and ``gc_s``, seconds in Python's garbage collector.
     """
 
     def __init__(self, cfg: ArchConfig, params, *, num_blocks: int,
@@ -284,10 +323,8 @@ class PagedServeEngine:
         self.queue: deque = deque()
         self.finished: list = []
         # incremental signal counters (metrics() never rescans)
-        self.ticks = 0
-        self.tokens_generated = 0
         self.slots_busy = 0
-        self._busy_ticks = 0
+        self._ticks = 0  # ticks run, to count each request's prefill ticks
         self._reserved = 0  # future block demand of active slots
         self._prefill_backlog = 0  # prompt tokens submitted, not yet fed
         self._prompt_tokens = 0  # admitted prompt tokens (hit-rate denom)
@@ -299,6 +336,7 @@ class PagedServeEngine:
         # logits its first token is sampled from
         self.on_first_logits: Optional[Callable[[Request, np.ndarray],
                                                 None]] = None
+        _time_gc()
 
     # ----------------------------------------------------------- admission
 
@@ -342,7 +380,8 @@ class PagedServeEngine:
             seq = SequenceBlocks(self.alloc)
             seq.adopt(blocks, n)
             self.slots[slot] = _PagedSlot(req=req, seq=seq, pos=n,
-                                          reserved=required)
+                                          reserved=required,
+                                          tick0=self._ticks)
             self._reserved += required
             self.slots_busy += 1
             self.peak_active = max(self.peak_active, self.slots_busy)
@@ -407,67 +446,95 @@ class PagedServeEngine:
 
     def step(self) -> list:
         """One engine tick: admit, then one mixed prefill/decode program."""
-        self._admit()
+        work = {"C": 0, "rows": 0, "fed": 0, "decoded": 0}
+        first: list = []
+        gc0 = _gc["total"]
+        with TraceAnnotation("serve.step") as span:
+            out = self._step(work, first)
+            if span.is_enabled():  # the step's work, in the trace
+                span.set_metadata(
+                    first=" ".join(f"{r}:{n}" for r, n in first),
+                    gc_s=_gc["total"] - gc0, **work)
+        return out
+
+    def _step(self, work: dict, first: list) -> list:
+        with TraceAnnotation("serve.admit"):
+            self._admit()
         active_idx = [i for i, s in enumerate(self.slots) if s is not None]
-        self.ticks += 1
-        self._busy_ticks += len(active_idx)
         if self.on_metrics is not None:
-            self.on_metrics(self.metrics())
+            with TraceAnnotation("serve.hook"):
+                self.on_metrics(self.metrics())
         if not active_idx:
             return []
-        prefilling = [i for i in active_idx
-                      if self.slots[i].pos < len(self.slots[i].req.prompt)]
-        C = self.prefill_chunk if prefilling else 1
-        feed = np.zeros((self.max_active, C), np.int32)
-        counts = np.zeros((self.max_active,), np.int32)
-        active = np.zeros((self.max_active,), bool)
-        issued: dict = {}
-        for i in active_idx:
-            s = self.slots[i]
-            P = len(s.req.prompt)
-            toks = (s.req.prompt[s.pos:s.pos + C] if s.pos < P
-                    else [s.next_token])
-            if not self._grow(s, len(toks)):
-                continue  # pool exhausted: the slot stalls this tick
-            self._table_row(i)
-            feed[i, :len(toks)] = toks
-            counts[i] = len(toks)
-            active[i] = True
-            issued[i] = len(toks)
-            s.seq.length += len(toks)
+        with TraceAnnotation("serve.prepare"):
+            prefilling = [i for i in active_idx
+                          if self.slots[i].pos < len(self.slots[i].req.prompt)]
+            C = self.prefill_chunk if prefilling else 1
+            feed = np.zeros((self.max_active, C), np.int32)
+            counts = np.zeros((self.max_active,), np.int32)
+            active = np.zeros((self.max_active,), bool)
+            issued: dict = {}
+            for i in active_idx:
+                s = self.slots[i]
+                P = len(s.req.prompt)
+                toks = (s.req.prompt[s.pos:s.pos + C] if s.pos < P
+                        else [s.next_token])
+                if not self._grow(s, len(toks)):
+                    continue  # pool exhausted: the slot stalls this tick
+                self._table_row(i)
+                n = len(toks)
+                feed[i, :n] = toks
+                counts[i] = n
+                active[i] = True
+                issued[i] = n
+                work["fed" if s.pos < P else "decoded"] += n
+                s.seq.length += n
         if not issued:
             return []
-        logits, self.state = self._tick(
-            self.params, self.state, jnp.asarray(self._tables),
-            jnp.asarray(feed), jnp.asarray(counts), jnp.asarray(active))
-        logits = np.asarray(logits)
+        self._ticks += 1
+        work["C"], work["rows"] = C, len(issued)
+        with TraceAnnotation("serve.dispatch"):
+            logits, self.state = self._tick(
+                self.params, self.state, jnp.asarray(self._tables),
+                jnp.asarray(feed), jnp.asarray(counts), jnp.asarray(active))
+        with TraceAnnotation("serve.tick_wait"):
+            logits.block_until_ready()
+        with TraceAnnotation("serve.logits_copy"):
+            logits = np.asarray(logits)
 
-        out = []
-        for i, n in issued.items():
-            s = self.slots[i]
-            P = len(s.req.prompt)
-            if s.pos < P:  # was prefilling
-                s.pos += n
-                self._prefill_backlog -= n
-                if s.pos == P:
-                    # prompt complete: sample the first token (fed next
-                    # tick — same semantics as ServeEngine._admit) and
-                    # publish the prompt's blocks for prefix reuse
-                    s.next_token = int(np.argmax(logits[i]))
-                    if self.on_first_logits is not None:
-                        self.on_first_logits(s.req, logits[i])
-                    if self.cache is not None:
-                        self.cache.insert(s.req.prompt, s.seq.blocks, P)
-            else:
-                tok = int(np.argmax(logits[i]))
-                s.req.generated.append(tok)
-                s.next_token = tok
-                out.append((s.req.rid, tok))
-                self.tokens_generated += 1
-                if len(s.req.generated) >= s.req.max_new_tokens:
-                    s.req.done = True
-                    self.finished.append(s.req)
-                    self._retire(i)
+        out, completed = [], []
+        with TraceAnnotation("serve.sample"):
+            for i, n in issued.items():
+                s = self.slots[i]
+                P = len(s.req.prompt)
+                if s.pos < P:  # was prefilling
+                    s.pos += n
+                    self._prefill_backlog -= n
+                    if s.pos == P:
+                        # prompt complete: sample the first token (fed next
+                        # tick — same semantics as ServeEngine._admit)
+                        s.next_token = int(np.argmax(logits[i]))
+                        completed.append(i)
+                        first.append((s.req.rid, self._ticks - s.tick0))
+                else:
+                    tok = int(np.argmax(logits[i]))
+                    s.req.generated.append(tok)
+                    s.next_token = tok
+                    out.append((s.req.rid, tok))
+                    if len(s.req.generated) >= s.req.max_new_tokens:
+                        s.req.done = True
+                        self.finished.append(s.req)
+                        self._retire(i)
+        if completed and self.cache is not None:
+            with TraceAnnotation("serve.cache_insert"):  # for prefix reuse
+                for i in completed:
+                    s = self.slots[i]
+                    self.cache.insert(s.req.prompt, s.seq.blocks,
+                                      len(s.req.prompt))
+        if completed and self.on_first_logits is not None:
+            with TraceAnnotation("serve.hook"):
+                for i in completed:
+                    self.on_first_logits(self.slots[i].req, logits[i])
         return out
 
     def run_until_drained(self, max_ticks: int = 10000) -> list:
@@ -488,16 +555,11 @@ class PagedServeEngine:
         return {
             "numSlots": self.max_active, "slotsBusy": self.slots_busy,
             "occupancy": self.slots_busy / self.max_active,
-            "meanOccupancy": (self._busy_ticks
-                              / (self.ticks * self.max_active)
-                              if self.ticks else 0.0),
             "queueDepth": len(self.queue),
             "backpressure": min(1.0, len(self.queue) / self.max_active),
-            "ticks": self.ticks, "tokensGenerated": self.tokens_generated,
             "finished": len(self.finished),
             "blocksTotal": self.alloc.capacity,
             "blocksFree": self.alloc.blocks_free,
-            "blocksReserved": self._reserved,
             "blocksCached": (self.cache.blocks_cached
                              if self.cache is not None else 0),
             "prefixHitRate": (self._cached_tokens / self._prompt_tokens
