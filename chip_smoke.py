@@ -14,7 +14,8 @@ tokens, the pairs hit the prefix cache, and the kernel path's first-token
 logits match the pure-jnp ``gather`` path's for the same prompts (run
 after it, so one pool exists at a time).  Before the engines, the compiled
 kernel alone is held to the plain f32 reference (``kernels.ref``) on a
-random pool of the served shape, at the served sequence lengths.
+random pool of the benchmark's shape (400 blocks, tables 399 wide), at the
+served sequence lengths plus a row of length 0 and a full 399-block row.
 
 Train phase: three steps of ``launch.train.train_direct``, unsharded, on
 qwen1.5-4b's published widths cut to 2 layers and an eighth of the
@@ -76,9 +77,11 @@ MAX_NEW = 32
 # late 1.34.
 LOGITS_RTOL = 5e-2
 # compiled kernel vs the f32 reference, same relative measure.  On a TPU
-# v5e: sound 0.0019; heads reversed 1.1, table one block late 0.71, mask
-# admitting the slot at ``length`` 0.25.
+# v5e the sound kernel reads 0.0025-0.0028; kernels that read the heads in
+# reverse, each table row one block late, or admit the slot at ``length``
+# read 1.1, 0.71 and 0.25.
 KERNEL_RTOL = 2e-2
+CHECK_BLOCKS = 400  # the benchmark's pool: the kernel check's shape
 # train phase: published widths, 2 layers, vocab / 8
 TRAIN_LAYERS = 2
 TRAIN_VOCAB_DIV = 8
@@ -170,31 +173,34 @@ def drive(engine, prompts, followers, max_new):
 
 def kernel_check(cfg, lengths, seed: int) -> float:
     """Compiled paged kernel against the f32 reference on a random bf16
-    pool of the served shape: each sequence of ``lengths`` holds its own
-    blocks, scattered over the pool.  Returns the largest |difference|
-    relative to the largest |reference output|."""
+    pool and table of the benchmark's served shape (``CHECK_BLOCKS``
+    blocks, tables one narrower): the rows of ``lengths``, one of length 0,
+    which must read as zeros, and one that fills its whole table row.  Each
+    row's table is its own draw of pool blocks.  Returns the largest
+    |difference| relative to the largest |reference output|, over the rows
+    of nonzero length."""
     KV, D = cfg.num_kv_heads, cfg.head_dim
-    nblk = [-(-n // BLOCK_SIZE) for n in lengths]
-    T = max(nblk)
-    perm = np.random.default_rng(seed).permutation(NUM_BLOCKS)
-    tables = np.zeros((len(lengths), T), np.int32)
-    offsets = np.cumsum([0] + nblk)
-    check(offsets[-1] <= NUM_BLOCKS, "kernel check fits the pool")
-    for b, n in enumerate(nblk):
-        tables[b, :n] = perm[offsets[b]:offsets[b] + n]
+    T = CHECK_BLOCKS - 1  # block 0 is the engine's scratch block
+    lengths = list(lengths) + [0, T * BLOCK_SIZE]
+    check(max(lengths) <= T * BLOCK_SIZE, "kernel check fits the table")
+    rng = np.random.default_rng(seed)
+    tables = np.stack([rng.permutation(T) + 1 for _ in lengths])
     kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
-    pool = (NUM_BLOCKS, KV, BLOCK_SIZE, D)
+    pool = (CHECK_BLOCKS, KV, BLOCK_SIZE, D)
     q = jax.random.normal(kq, (len(lengths), cfg.num_heads, D), jnp.bfloat16)
     k = jax.random.normal(kk, pool, jnp.bfloat16)
     v = jax.random.normal(kv, pool, jnp.bfloat16)
-    tables, lens = jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+    tables = jnp.asarray(tables, jnp.int32)
+    lens = jnp.asarray(lengths, jnp.int32)
     got = np.asarray(paged_decode_attention(q, k, v, tables, lens)
                      .astype(jnp.float32))
+    live = np.asarray(lengths) > 0
     with jax.default_matmul_precision("highest"):
         want = np.asarray(paged_decode_attention_ref(
-            q.astype(jnp.float32), k, v, tables, lens))
+            q[live].astype(jnp.float32), k, v, tables[live], lens[live]))
     check(bool(np.all(np.isfinite(got))), "kernel output finite")
-    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+    check(bool(np.all(got[~live] == 0)), "a row of length 0 reads zeros")
+    return float(np.max(np.abs(got[live] - want)) / np.max(np.abs(want)))
 
 
 def serve_phase(seed: int, clock: CompileClock) -> None:

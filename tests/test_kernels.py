@@ -14,6 +14,7 @@ from repro.kernels import (
     rglru_scan,
     rmsnorm,
 )
+from repro.kernels.decode_attention import blocks_per_wave
 
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
@@ -88,31 +89,93 @@ def test_decode_attention_unaligned_cache(B, H, KV, D, Smax, block_k):
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("B,H,KV,D,bs,T", [
-    (2, 8, 2, 64, 16, 8),   # GQA
-    (3, 4, 1, 128, 32, 4),  # MQA
-    (1, 4, 4, 64, 8, 16),   # MHA
+def _ragged(P, bs, T, B):
+    # the original sweep's lengths: the longest row fills the table
+    return [max(1, (T * bs) // (i + 1) - 3) for i in range(B)]
+
+
+# lengths are drawn from the derived wave size P, the block size and T
+@pytest.mark.parametrize("B,H,KV,D,bs,T,lens", [
+    pytest.param(2, 8, 2, 64, 16, 8, _ragged, id="2-8-2-64-16-8"),  # GQA
+    pytest.param(3, 4, 1, 128, 32, 4, _ragged, id="3-4-1-128-32-4"),  # MQA
+    pytest.param(1, 4, 4, 64, 8, 16, _ragged, id="1-4-4-64-8-16"),  # MHA
+    pytest.param(3, 8, 8, 128, 16, 24,
+                 lambda P, bs, T, B: [T * bs, 0, T * bs - 5],
+                 id="len0-between-full-rows"),
+    pytest.param(3, 8, 8, 128, 16, 40,
+                 lambda P, bs, T, B: [3 * bs, P * bs, 2 * P * bs],
+                 id="block-and-wave-boundaries"),
+    pytest.param(3, 32, 8, 128, 16, 40,
+                 lambda P, bs, T, B: [(P + 3) * bs - 1, (2 * P + 1) * bs, 5],
+                 id="gqa-blocks-not-multiple-of-wave"),
+    pytest.param(2, 8, 8, 128, 16, 51,
+                 lambda P, bs, T, B: [T * bs, T * bs - 7],
+                 id="table-not-multiple-of-wave"),
+    pytest.param(3, 8, 8, 128, 16, 72,
+                 lambda P, bs, T, B: [T * bs, 40 * bs + 3, 1],
+                 id="table-of-72-blocks"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_attention_parity(B, H, KV, D, bs, T, dtype):
+def test_paged_decode_attention_parity(B, H, KV, D, bs, T, lens, dtype):
     """Paged kernel vs gather-then-dense oracle, with shuffled non-identity
     block tables and ragged lengths (some rows pointing at scratch)."""
     num_blocks = B * T + 1  # + scratch block 0
+    P = blocks_per_wave(KV, bs, D, jnp.dtype(dtype).itemsize, T)
     ks = jax.random.split(jax.random.key(hash((B, H, KV, D, bs)) % 2**31), 4)
     q = rand(ks[0], (B, H, D), dtype)
     k_pool = rand(ks[1], (num_blocks, KV, bs, D), dtype)
     v_pool = rand(ks[2], (num_blocks, KV, bs, D), dtype)
     perm = jax.random.permutation(ks[3], num_blocks - 1) + 1
     tables = perm.reshape(B, T).astype(jnp.int32)
-    lengths = jnp.asarray([max(1, (T * bs) // (i + 1) - 3) for i in range(B)],
-                          jnp.int32)
+    lengths = jnp.asarray(lens(P, bs, T, B), jnp.int32)
     # unused trailing table entries point at scratch, as the engine leaves them
     used = -(-lengths // bs)  # ceil-div: blocks actually referenced
     tables = jnp.where(jnp.arange(T)[None, :] < used[:, None], tables, 0)
     out = paged_decode_attention(q, k_pool, v_pool, tables, lengths,
                                  interpret=True)
     want = ref.paged_decode_attention_ref(q, k_pool, v_pool, tables, lengths)
+    # a row of length 0 attends nothing: zeros, where the oracle has NaN
+    live = lengths > 0
+    want = jnp.where(live[:, None, None], want, 0)
     np.testing.assert_allclose(out.astype(np.float32), want.astype(np.float32),
+                               atol=TOL[dtype], rtol=TOL[dtype])
+
+
+def test_paged_decode_attention_never_reads_dead_blocks():
+    """Every pool block no row references, and the tail of each row's last
+    block past its length, hold NaN: the output still equals the oracle on
+    clean data, and a row of length 0 gives zeros."""
+    B, H, KV, D, bs, T = 4, 8, 8, 128, 16, 40
+    dtype = jnp.bfloat16
+    P = blocks_per_wave(KV, bs, D, jnp.dtype(dtype).itemsize, T)
+    assert T > 2 * P  # several waves, both buffer slots
+    num_blocks = B * T + 1
+    ks = jax.random.split(jax.random.key(21), 4)
+    q = rand(ks[0], (B, H, D), dtype)
+    k_pool = rand(ks[1], (num_blocks, KV, bs, D), dtype)
+    v_pool = rand(ks[2], (num_blocks, KV, bs, D), dtype)
+    tables = (jax.random.permutation(ks[3], num_blocks - 1) + 1).reshape(B, T)
+    lengths = jnp.asarray([T * bs - 3, 0, (P + 1) * bs + 5, 7], jnp.int32)
+    used = -(-lengths // bs)
+    live_blk = jnp.arange(T)[None, :] < used[:, None]
+    tables = jnp.where(live_blk, tables, 0).astype(jnp.int32)
+    want = ref.paged_decode_attention_ref(q, k_pool, v_pool, tables, lengths)
+
+    # per pool block and position: is it a live token of some row?
+    pos = jnp.arange(T)[:, None] * bs + jnp.arange(bs)[None, :]  # (T, bs)
+    token_live = live_blk[:, :, None] & (pos[None] < lengths[:, None, None])
+    keep = jnp.zeros((num_blocks, bs), bool).at[tables].max(token_live)
+    dead = ~keep[:, None, :, None]
+    k_bad = jnp.where(dead, jnp.nan, k_pool).astype(dtype)
+    v_bad = jnp.where(dead, jnp.nan, v_pool).astype(dtype)
+    out = paged_decode_attention(q, k_bad, v_bad, tables, lengths,
+                                 interpret=True)
+    out = np.asarray(out.astype(np.float32))
+    assert not np.isnan(out).any()
+    np.testing.assert_array_equal(out[1], 0)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(out[live],
+                               np.asarray(want.astype(np.float32))[live],
                                atol=TOL[dtype], rtol=TOL[dtype])
 
 

@@ -41,12 +41,15 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("H,KV", [
-    (20, 20),  # qwen1.5-4b: MHA, 20 heads x 128
-    (32, 4),   # GQA, 8 query heads per KV head
+@pytest.mark.parametrize("H,KV,T,num_blocks", [
+    pytest.param(20, 20, 64, 513, id="20-20"),  # qwen1.5-4b: MHA, 20 x 128
+    pytest.param(32, 4, 64, 513, id="32-4"),  # GQA, 8 query heads a KV head
+    # the benchmark's served shape: a 400-block pool, tables 399 wide
+    pytest.param(20, 20, 399, 400, id="20-20-served"),
 ])
-def test_paged_decode_attention_compiles_for_v5e(one_chip, H, KV):
-    B, D, block_size, T, num_blocks = 8, 128, 16, 64, 513
+def test_paged_decode_attention_compiles_for_v5e(one_chip, H, KV, T,
+                                                 num_blocks):
+    B, D, block_size = 8, 128, 16
     dt = jnp.bfloat16
 
     def arg(shape, dtype):
