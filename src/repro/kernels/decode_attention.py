@@ -15,19 +15,21 @@ Grid: (B, KV, Smax/block_k), cache axis innermost (sequential).
 
 ``paged_decode_attention`` is the same online softmax over a *paged*
 cache: K/V live in a block pool ``(num_blocks, KV, block_size, D)`` that
-stays in HBM, and each sequence names its blocks through a block table
-delivered, with ``lengths``, as a scalar-prefetch operand (SMEM).  The
-grid has one step per sequence, and a step walks only that sequence's
-live blocks, ``cdiv(length, block_size)`` of them: a loop with a trip
-count set by the data, so blocks past the length cost nothing.  The blocks
-come in waves of ``P`` pool blocks, each copied by its own DMA, picked by
-the table, into one slot of a double buffer ``(2, KV, P, block_size, D)``
-(one for K, one for V); the next wave, or the next non-empty sequence's
-first, is in flight while one is computed.  ``P`` follows from shapes
-alone (``blocks_per_wave``: about 1 MiB of K+V a wave, in whole 128-lane
-rows of scores).  A wave is computed for all KV heads at once, as dots
-batched over heads (scores ``(KV, G, P * block_size)``, running max and
-sum ``(KV, G, 1)``, accumulator ``(KV, G, D)``, all float32); positions
+stays in HBM, or in a stack of layers' pools ``(L, num_blocks, KV,
+block_size, D)`` read in place at one layer, and each sequence names its
+blocks through a block table delivered, with ``lengths`` and the layer, as
+scalar-prefetch operands (SMEM).  The grid has one step per sequence, and
+a step walks only that sequence's live blocks, ``cdiv(length,
+block_size)`` of them: a loop with a trip count set by the data, so blocks
+past the length cost nothing.  The blocks come in waves of ``P`` pool
+blocks, each copied by its own DMA, picked by the table, into one slot of
+a double buffer ``(2, KV, P, block_size, D)`` (one for K, one for V); the
+next wave, or the next non-empty sequence's first, is in flight while one
+is computed.  ``P`` follows from shapes alone (``blocks_per_wave``: about
+1 MiB of K+V a wave, in whole 128-lane rows of scores).  A wave is
+computed for all KV heads at once, as dots batched over heads (scores
+``(KV, G, P * block_size)``, running max and sum ``(KV, G, 1)``,
+accumulator ``(KV, G, D)``, all float32); positions
 past the length are dropped by a select, so stale or dead data in a slot
 never reaches the result.  A sequence of length 0 issues no DMA and gives
 zeros.  Block 0 of the pool is the engine's scratch block; table entries
@@ -157,18 +159,20 @@ def blocks_per_wave(num_kv: int, block_size: int, head_dim: int,
     return min(p, table_width)
 
 
-def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sems, slot_ref, m_ref, l_ref, acc_ref,
-                         *, scale: float, wave: int):
-    # one grid step per row; the pools stay in HBM and the row's live
-    # blocks come in waves of ``wave`` blocks, double-buffered: wave w+1
-    # (or the next non-empty row's first wave) is in flight while wave w is
-    # computed.  tab_ref / len_ref are the scalar-prefetch operands (SMEM);
-    # slot_ref (SMEM) carries the buffer slot across grid steps.
+def _paged_decode_kernel(tab_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, k_buf, v_buf, sems, slot_ref, m_ref, l_ref,
+                         acc_ref, *, scale: float, wave: int):
+    # one grid step per row; the stacked pools stay in HBM and the row's
+    # live blocks of layer ``layer_ref[0]`` come in waves of ``wave``
+    # blocks, double-buffered: wave w+1 (or the next non-empty row's first
+    # wave) is in flight while wave w is computed.  tab_ref / len_ref /
+    # layer_ref are the scalar-prefetch operands (SMEM); slot_ref (SMEM)
+    # carries the buffer slot across grid steps.
     b = pl.program_id(0)
     B, T = tab_ref.shape
     bs = k_buf.shape[3]
     span = wave * bs
+    layer = layer_ref[0]
 
     def live_blocks(r):
         return jnp.minimum(pl.cdiv(len_ref[r], bs), T)
@@ -178,10 +182,10 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         # issue none
         def one(j, _):
             blk = tab_ref[r, w * wave + j]
-            fn(pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[slot, :, j],
-                                     sems.at[0, slot]))
-            fn(pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[slot, :, j],
-                                     sems.at[1, slot]))
+            fn(pltpu.make_async_copy(k_hbm.at[layer, blk],
+                                     k_buf.at[slot, :, j], sems.at[0, slot]))
+            fn(pltpu.make_async_copy(v_hbm.at[layer, blk],
+                                     v_buf.at[slot, :, j], sems.at[1, slot]))
             return 0
 
         jax.lax.fori_loop(0, jnp.minimum(wave, live_blocks(r) - w * wave),
@@ -253,31 +257,36 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                           interpret: bool = False):
+def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
+                           layer=None, *, interpret: bool = False):
     """Decode attention over a paged KV cache.
 
-    q (B,H,D); pools (num_blocks, KV, block_size, D); block_tables (B,T)
+    q (B,H,D); pools (num_blocks, KV, block_size, D), or a stack of
+    layers' pools (L, num_blocks, KV, block_size, D) of which ``layer``
+    (an int32 scalar, traced or not) is read in place; block_tables (B,T)
     int32 (physical block of each sequence's t-th logical block); lengths
-    (B,) -> (B,H,D).  One grid step per sequence walks its live blocks
-    only, ``blocks_per_wave`` of them per double-buffered DMA wave, all KV
-    heads at once; no contiguous (B, Smax, KV, D) cache ever exists
-    on-device.  A sequence of length 0 reads nothing and gives zeros.
+    (B,) -> (B,H,D).  One grid step per sequence walks its live blocks only,
+    ``blocks_per_wave`` of them per double-buffered DMA wave, all KV heads
+    at once; no contiguous (B, Smax, KV, D) cache ever exists on-device.
+    A sequence of length 0 reads nothing and gives zeros.
     """
+    if k_pool.ndim == 4:  # one layer's pool: a stack of one
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     B, H, D = q.shape
-    _, KV, block_size, _ = k_pool.shape
+    _, _, KV, block_size, _ = k_pool.shape
     T = block_tables.shape[1]
     G = H // KV
     wave = blocks_per_wave(KV, block_size, D, k_pool.dtype.itemsize, T)
     qg = q.reshape(B, KV, G, D)
     tab = block_tables.astype(jnp.int32)
     lens = lengths.astype(jnp.int32)
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
 
     kernel = functools.partial(_paged_decode_kernel,
                                scale=1.0 / math.sqrt(D), wave=wave)
-    row = pl.BlockSpec((1, KV, G, D), lambda b, tab, lens: (b, 0, 0, 0))
+    row = pl.BlockSpec((1, KV, G, D), lambda b, *_: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block tables + lengths land in SMEM
+        num_scalar_prefetch=3,  # block tables, lengths, layer land in SMEM
         grid=(B,),
         in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
@@ -301,5 +310,5 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(tab, lens, qg, k_pool, v_pool)
+    )(tab, lens, lyr, qg, k_pool, v_pool)
     return out.reshape(B, H, D)

@@ -18,6 +18,11 @@ replaces the fixed-slot engine's O(prompt) per-token admit/merge loop and
 bounds the tail-latency impact of admission on running requests to
 ``C - 1`` masked micro-steps.
 
+The scanned group's pools stay stacked ``(G, num_blocks, KV, block_size,
+D)`` and ride in the layer scan's carry: each layer writes its new rows
+into the stack and the kernel reads the layer from it in place, so no
+pool is sliced, laid out again or copied whole inside the tick.
+
 Block 0 of every pool is scratch: inactive rows write there and mask their
 outputs, so no per-slot control flow exists inside the program.
 """
@@ -97,9 +102,27 @@ def _mask_tree(new, old, adv):
     return jax.tree.map(pick, new, old)
 
 
-def _paged_attn_layer(lparams, cfg, spec, state, x, sin, cos, lengths, adv,
-                      tables, opts, attn_impl, interpret):
-    """The attn branch of ``lm._decode_layer`` against the block pool."""
+def _write_rows(pool, lead, blk, off, rows):
+    """Row ``b`` of ``rows`` (B, KV, D) into ``pool[lead + (blk[b], :,
+    off[b])]``, one dynamic-update-slice a row: XLA writes each in place in
+    the pool's own layout, where one scatter of all rows would have the
+    pool laid out again (KV beside D) and copied whole around the write."""
+    B, KV, D = rows.shape
+    for b in range(B):
+        upd = rows[b].reshape((1,) * (len(lead) + 1) + (KV, 1, D))
+        pool = jax.lax.dynamic_update_slice(pool, upd.astype(pool.dtype),
+                                            lead + (blk[b], 0, off[b], 0))
+    return pool
+
+
+def _paged_attn_layer(lparams, cfg, spec, pool, layer, x, sin, cos, lengths,
+                      adv, tables, opts, attn_impl, interpret):
+    """The attn branch of ``lm._decode_layer`` against the block pool.
+
+    ``pool`` is one layer's ``{"k", "v"}`` pools ``(N, KV, bs, D)`` with
+    ``layer`` None, or the scanned group's stacked pools ``(G, N, KV, bs,
+    D)`` with ``layer`` the index into them: the new rows are written into
+    the stack and the layer is read from it in place, never sliced out."""
     dt = x.dtype
     h = rmsnorm(x, lparams["norm1"]["scale"], cfg.norm_eps)
     ap = lparams["attn"]
@@ -115,23 +138,25 @@ def _paged_attn_layer(lparams, cfg, spec, state, x, sin, cos, lengths, adv,
     q = apply_rope(q, sin, cos)
     k = apply_rope(k, sin, cos)
 
-    _, KV, bs, D = state["k"].shape
+    KV, bs, D = pool["k"].shape[-3:]
+    lead = () if layer is None else (layer,)
     bidx = jnp.arange(x.shape[0])
     # inactive rows write the scratch block (0, :, 0); their output is
     # ignored by the caller, so no gather/scatter is ever masked out
     blk = jnp.where(adv, tables[bidx, lengths // bs], 0)
     off = jnp.where(adv, lengths % bs, 0)
-    new_k = state["k"].at[blk, :, off].set(k)  # k (B, KV, D)
-    new_v = state["v"].at[blk, :, off].set(v)
+    pool = {"k": _write_rows(pool["k"], lead, blk, off, k),
+            "v": _write_rows(pool["v"], lead, blk, off, v)}
 
     if attn_impl == "kernel":
-        out = paged_decode_attention(q, new_k, new_v, tables, lengths + 1,
-                                     interpret=interpret)
+        out = paged_decode_attention(q, pool["k"], pool["v"], tables,
+                                     lengths + 1, layer, interpret=interpret)
     else:  # pure-jnp gather: XLA materializes each slot's view on gather
         B = x.shape[0]
-        kc = new_k[tables].transpose(0, 1, 3, 2, 4).reshape(B, -1, KV, D)
-        vc = new_v[tables].transpose(0, 1, 3, 2, 4).reshape(B, -1, KV, D)
-        out = decode_attention_jnp(q, kc, vc, lengths + 1)
+        kc = pool["k"][lead + (tables,)].transpose(0, 1, 3, 2, 4)
+        vc = pool["v"][lead + (tables,)].transpose(0, 1, 3, 2, 4)
+        out = decode_attention_jnp(q, kc.reshape(B, -1, KV, D),
+                                   vc.reshape(B, -1, KV, D), lengths + 1)
     mix = jnp.einsum("bhe,hed->bd", out, ap["wo"].astype(dt))
     x = x + mix
     if spec.use_moe:
@@ -141,14 +166,14 @@ def _paged_attn_layer(lparams, cfg, spec, state, x, sin, cos, lengths, adv,
     elif spec.d_ff > 0:
         h2 = rmsnorm(x, lparams["norm2"]["scale"], cfg.norm_eps)
         x = x + mlp_apply(lparams["mlp"], h2, cfg.act, cfg.gated_mlp)
-    return x, {"k": new_k, "v": new_v}
+    return x, pool
 
 
-def _paged_layer(lparams, cfg, spec, state, x, sin, cos, lengths, adv,
+def _paged_layer(lparams, cfg, spec, state, layer, x, sin, cos, lengths, adv,
                  tables, opts, attn_impl, interpret):
     if _is_paged(spec):
-        return _paged_attn_layer(lparams, cfg, spec, state, x, sin, cos,
-                                 lengths, adv, tables, opts, attn_impl,
+        return _paged_attn_layer(lparams, cfg, spec, state, layer, x, sin,
+                                 cos, lengths, adv, tables, opts, attn_impl,
                                  interpret)
     x2, ns = _decode_layer(lparams, cfg, spec, state, x, sin, cos, lengths,
                            opts)
@@ -170,28 +195,41 @@ def _paged_decode_step(params, cfg, state, tables, tokens, adv, opts,
                  "prefix": [], "tail": [], "main": state["main"]}
 
     for lp, spec, st in zip(params["prefix"], plan.prefix, state["prefix"]):
-        x, ns = _paged_layer(lp, cfg, spec, st, x, sin, cos, lengths, adv,
-                             tables, opts, attn_impl, interpret)
+        x, ns = _paged_layer(lp, cfg, spec, st, None, x, sin, cos, lengths,
+                             adv, tables, opts, attn_impl, interpret)
         new_state["prefix"].append(ns)
 
     if plan.num_groups:
-        def group_body(x, scanned):
-            group_params, group_state = scanned
-            new_states = []
+        # the stacked pools ride in the carry, so every layer writes and
+        # reads them in place; per-slot state and weights are scanned
+        paged = [_is_paged(s) for s in plan.pattern]
+        pools = [e if p else None for p, e in zip(paged, state["main"])]
+        slots = [None if p else e for p, e in zip(paged, state["main"])]
+
+        def group_body(carry, scanned):
+            x, pools = carry
+            g, group_params, group_slots = scanned
+            pools, new_slots = list(pools), []
             for i, spec in enumerate(plan.pattern):
                 x, ns = _paged_layer(group_params[i], cfg, spec,
-                                     group_state[i], x, sin, cos, lengths,
-                                     adv, tables, opts, attn_impl, interpret)
-                new_states.append(ns)
-            return x, new_states
+                                     pools[i] if paged[i] else group_slots[i],
+                                     g, x, sin, cos, lengths, adv, tables,
+                                     opts, attn_impl, interpret)
+                if paged[i]:
+                    pools[i], ns = ns, None
+                new_slots.append(ns)
+            return (x, pools), new_slots
 
-        x, new_main = jax.lax.scan(group_body, x,
-                                   (params["main"], state["main"]))
-        new_state["main"] = new_main
+        (x, pools), slots = jax.lax.scan(
+            group_body, (x, pools),
+            (jnp.arange(plan.num_groups, dtype=jnp.int32), params["main"],
+             slots))
+        new_state["main"] = [e if p else s
+                             for p, e, s in zip(paged, pools, slots)]
 
     for lp, spec, st in zip(params["tail"], plan.tail, state["tail"]):
-        x, ns = _paged_layer(lp, cfg, spec, st, x, sin, cos, lengths, adv,
-                             tables, opts, attn_impl, interpret)
+        x, ns = _paged_layer(lp, cfg, spec, st, None, x, sin, cos, lengths,
+                             adv, tables, opts, attn_impl, interpret)
         new_state["tail"].append(ns)
 
     x = rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)

@@ -198,6 +198,65 @@ def test_paged_matches_dense_same_tokens():
     np.testing.assert_allclose(paged, dense, atol=2e-5, rtol=2e-5)
 
 
+def _stacked_pools(L, dtype, key):
+    """A stack of L layers' pools with shuffled tables: ragged rows, a row
+    of length 0, and one that crosses a wave boundary."""
+    B, H, KV, D, bs, T = 4, 8, 4, 128, 16, 24
+    num_blocks = B * T + 1
+    P = blocks_per_wave(KV, bs, D, jnp.dtype(dtype).itemsize, T)
+    ks = jax.random.split(key, 4)
+    q = rand(ks[0], (B, H, D), dtype)
+    k = rand(ks[1], (L, num_blocks, KV, bs, D), dtype)
+    v = rand(ks[2], (L, num_blocks, KV, bs, D), dtype)
+    tables = (jax.random.permutation(ks[3], num_blocks - 1) + 1).reshape(B, T)
+    lengths = jnp.asarray([T * bs - 3, 0, (P + 1) * bs + 5, 7], jnp.int32)
+    used = -(-lengths // bs)
+    tables = jnp.where(jnp.arange(T)[None, :] < used[:, None], tables, 0)
+    return q, k, v, tables.astype(jnp.int32), lengths
+
+
+@pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_attention_stacked_pool(layer, dtype):
+    """A stack of layers' pools read in place at one layer (the index a
+    traced scalar) equals the oracle on that layer's pool, and the one-pool
+    entry point on the same pool bit for bit."""
+    q, k, v, tables, lengths = _stacked_pools(5, dtype, jax.random.key(31))
+    out = paged_decode_attention(q, k, v, tables, lengths, jnp.int32(layer),
+                                 interpret=True)
+    want = ref.paged_decode_attention_ref(q, k[layer], v[layer], tables,
+                                          lengths)
+    want = jnp.where((lengths > 0)[:, None, None], want, 0)
+    np.testing.assert_allclose(out.astype(np.float32),
+                               want.astype(np.float32),
+                               atol=TOL[dtype], rtol=TOL[dtype])
+    one = paged_decode_attention(q, k[layer], v[layer], tables, lengths,
+                                 interpret=True)
+    np.testing.assert_array_equal(np.asarray(out.astype(np.float32)),
+                                  np.asarray(one.astype(np.float32)))
+
+
+def test_paged_decode_attention_stacked_ignores_other_layers():
+    """Every block of every layer but the one read holds NaN: nothing of
+    them reaches the result."""
+    dtype, layer = jnp.bfloat16, 1
+    q, k, v, tables, lengths = _stacked_pools(3, dtype, jax.random.key(32))
+    other = (jnp.arange(3) != layer)[:, None, None, None, None]
+    k_bad = jnp.where(other, jnp.nan, k).astype(dtype)
+    v_bad = jnp.where(other, jnp.nan, v).astype(dtype)
+    out = paged_decode_attention(q, k_bad, v_bad, tables, lengths, layer,
+                                 interpret=True)
+    out = np.asarray(out.astype(np.float32))
+    assert not np.isnan(out).any()
+    want = ref.paged_decode_attention_ref(q, k[layer], v[layer], tables,
+                                          lengths)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_array_equal(out[~live], 0)
+    np.testing.assert_allclose(out[live],
+                               np.asarray(want.astype(np.float32))[live],
+                               atol=TOL[dtype], rtol=TOL[dtype])
+
+
 @pytest.mark.parametrize("B,S,C,bt", [(2, 128, 128, 16), (4, 64, 256, 8),
                                       (1, 256, 128, 64)])
 def test_rglru_scan_sweep(B, S, C, bt):
